@@ -65,6 +65,7 @@ import jax.numpy as jnp
 from repro.configs.base import GLMConfig
 from repro.core import DGLMNETOptions, fit_python_loop, lambda_max, regularization_path
 from repro.data.synthetic import make_glm_dataset
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def seed_style_path(X, y, path_len: int, opts: DGLMNETOptions):
@@ -409,6 +410,7 @@ def run(*, n: int = 2048, p: int = 4096, path_len: int = 20,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--tiny", action="store_true",
                     help="CI smoke shapes (seconds, not minutes)")

@@ -10,11 +10,13 @@ from repro.api import DenseDesign, LogisticL1, SlabDesign, lambda_max_design
 from repro.configs.base import GLMConfig
 from repro.core import DGLMNETOptions
 from repro.data.synthetic import make_glm_dataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import observe, render_summary
 from repro.train.metrics import glm_eval_fn
 
 
 def main():
+    enable_compile_cache()
     cfg = GLMConfig(name="quickstart", num_examples=8192, num_features=256,
                     density=1.0)
     ds = make_glm_dataset(cfg, jax.random.key(0))

@@ -38,11 +38,13 @@ from repro.configs.base import GLMConfig  # noqa: E402
 from repro.core import DGLMNETOptions, TGOptions  # noqa: E402
 from repro.core.truncated_gradient import truncated_gradient_fit  # noqa: E402
 from repro.data.synthetic import make_glm_dataset  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_dev_mesh  # noqa: E402
 from repro.train.metrics import auprc  # noqa: E402
 
 
 def main():
+    enable_compile_cache()
     cfg = GLMConfig(name="dist", num_examples=16384, num_features=1024,
                     density=0.2)
     ds = make_glm_dataset(cfg, jax.random.key(0))

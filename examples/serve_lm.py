@@ -12,11 +12,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import MODEL_CONFIGS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_cache, init_params
 from repro.train import make_prefill_step, make_serve_step
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b", choices=list(MODEL_CONFIGS))
     ap.add_argument("--batch", type=int, default=4)

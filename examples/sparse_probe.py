@@ -14,11 +14,13 @@ import numpy as np
 from repro.configs import MODEL_CONFIGS
 from repro.core.dglmnet import DGLMNETOptions
 from repro.core.probe import extract_features, probe_path
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.train.metrics import glm_eval_fn
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b", choices=list(MODEL_CONFIGS))
     ap.add_argument("--n", type=int, default=1024)

@@ -17,6 +17,7 @@ from repro.checkpoint import save_pytree
 from repro.configs import MODEL_CONFIGS
 from repro.configs.base import AttentionConfig, ModelConfig
 from repro.data.lm_data import batches, zipf_corpus
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import warmup_cosine
 from repro.train import make_train_state, make_train_step
 
@@ -31,6 +32,7 @@ LM100M = ModelConfig(
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="lm-100m",
                     choices=["lm-100m"] + list(MODEL_CONFIGS))

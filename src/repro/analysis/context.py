@@ -27,7 +27,6 @@ from typing import Dict, Iterator, List, Optional, Sequence, Set, Tuple
 MESH_IMPORT_ROOTS = (
     "jax.sharding",
     "jax.experimental.shard_map",
-    "repro.compat.shard_map",
     "repro.sharding",
 )
 

@@ -1,62 +1,15 @@
-"""Version-compat shims for JAX API surface that moved between releases.
-
-The repo targets the modern API (``jax.shard_map``, varying-manual-axes
-typing via ``vma``, ``jax.sharding.AxisType``); older installs (<= 0.4.x)
-expose the same functionality under ``jax.experimental.shard_map`` with
-``check_rep`` and no vma typing. Everything that touches those surfaces
-goes through this module so the rest of the codebase reads as
-current-API-only.
-"""
+"""Two JAX helpers shared by the kernels and the mesh constructors."""
 from __future__ import annotations
 
-import inspect
-from typing import Optional
-
 import jax
-
-HAS_SHARD_MAP = hasattr(jax, "shard_map")
-HAS_PCAST = hasattr(jax.lax, "pcast")
-HAS_VMA = "vma" in inspect.signature(jax.ShapeDtypeStruct.__init__).parameters
-HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` where available, else the experimental spelling.
-
-    ``check_vma`` maps onto the old ``check_rep``; the legacy replication
-    checker predates pcast/vma annotations and rejects scan carries whose
-    replication changes mid-loop (exactly our residual carry), so on old
-    JAX the check is disabled rather than half-translated — numerics are
-    covered by the distributed-vs-local equivalence tests.
-    """
-    if HAS_SHARD_MAP:
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )
-
-
-def pcast_varying(x, axis_name: str):
-    """Mark ``x`` as varying over ``axis_name`` (no-op before vma typing)."""
-    if HAS_PCAST:
-        return jax.lax.pcast(x, axis_name, to="varying")
-    return x
 
 
 def out_shape_struct(shape, dtype, operands=()):
     """``jax.ShapeDtypeStruct`` carrying the joint vma of ``operands``.
 
     Under ``shard_map(check_vma=True)`` a ``pallas_call`` out_shape must
-    declare the mesh axes its outputs vary over; older JAX has neither the
-    kwarg nor ``jax.typeof``, where the plain struct is correct.
+    declare the mesh axes its outputs vary over.
     """
-    if not HAS_VMA:
-        return jax.ShapeDtypeStruct(shape, dtype)
     vma = frozenset()
     for operand in operands:
         try:
@@ -66,20 +19,9 @@ def out_shape_struct(shape, dtype, operands=()):
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
-def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict (pre-0.5 JAX returned a
-    one-dict-per-device list)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost else {}
-    return cost
-
-
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types when the install has them."""
-    if HAS_AXIS_TYPE:
-        return jax.make_mesh(
-            axis_shapes, axis_names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
-        )
-    return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+    )

@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import pcast_varying, shard_map
 from repro.core import engine
 from repro.core.dglmnet import DGLMNETOptions
 from repro.core.subproblem import make_tile_solver
@@ -58,7 +57,7 @@ def local_subproblem(X_loc, w_loc, r, beta_loc, lam, *, tile: int, nu: float,
         # it so the scan carry type is stable (shard_map vma tracking). The
         # Pallas-kernel path runs with check_vma=False (interpret-mode scan
         # internals mix varying axes), where pcast is unavailable.
-        r = pcast_varying(r, "model")
+        r = jax.lax.pcast(r, "model", to="varying")
     tile_solver = make_tile_solver(cycle_mode=cycle_mode, tile=tile,
                                    block=block, use_kernel=use_kernel)
 
@@ -115,7 +114,7 @@ def local_subproblem_sparse(row_idx, values, w_loc, r, beta_loc, lam, *,
     p_loc, k = row_idx.shape
     assert p_loc % tile == 0, (p_loc, tile)
     nt = p_loc // tile
-    r = pcast_varying(r, "model")
+    r = jax.lax.pcast(r, "model", to="varying")
     tile_solver = make_tile_solver(cycle_mode=cycle_mode, tile=tile,
                                    block=block)
 
@@ -192,7 +191,7 @@ def make_distributed_iteration_sparse(mesh: Mesh, opts: DGLMNETOptions, *,
     dspec = P(daxes) if daxes else P()
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(model_axis, daxes, None), P(model_axis, daxes, None),
                   P(model_axis), dspec, dspec, P()),
@@ -246,7 +245,7 @@ def make_slab_margins(mesh: Mesh, n_loc: int, model_axis: str = "model"):
 
     @jax.jit
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(model_axis, daxes, None), P(model_axis, daxes, None),
                   P(model_axis)),
@@ -272,7 +271,7 @@ def make_slab_densifier(mesh: Mesh, n_loc: int, model_axis: str = "model"):
 
     @jax.jit
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(model_axis, daxes, None), P(model_axis, daxes, None)),
         out_specs=P(daxes, model_axis),
@@ -297,7 +296,7 @@ def make_distributed_iteration(mesh: Mesh, opts: DGLMNETOptions, *,
     dspec = P(daxes) if daxes else P()
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(daxes, model_axis), P(model_axis), dspec, dspec, P()),
         out_specs=(P(model_axis), dspec),

@@ -150,14 +150,13 @@ def _sparse_corr_program(mesh: Mesh, n_loc: int, tile: int,
     ``Design.correlation``: ``corr(row_idx, values, v) -> X^T v`` (signed),
     feature-sharded P(model). Un-jitted so callers can fuse it into their
     own programs; see :func:`make_sparse_corr` for the jitted form."""
-    from repro.compat import shard_map
     from repro.core.distributed import _data_axes
 
     daxes = _data_axes(mesh)
     dspec = P(daxes) if daxes else P()
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(model_axis, daxes, None), P(model_axis, daxes, None),
                   dspec),

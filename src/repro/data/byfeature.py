@@ -51,15 +51,18 @@ class ByFeature:
 
 def to_by_feature(X) -> ByFeature:
     """Dense (n, p) -> by-feature padded CSC (the Reduce step of paper §3)."""
-    Xn = np.asarray(X)
-    n, p = Xn.shape
-    cols = [np.nonzero(Xn[:, j])[0] for j in range(p)]
-    k = max((len(c) for c in cols), default=1) or 1
+    # one vectorized pass over the feature-major copy: rows come out
+    # ascending within each feature, exactly the per-column nonzero order
+    Xt = np.ascontiguousarray(np.asarray(X).T)
+    p, n = Xt.shape
+    feat, rows = np.nonzero(Xt)
+    counts = np.bincount(feat, minlength=p)
+    k = max(int(counts.max()) if p else 0, 1)
+    rank = np.arange(feat.size) - (np.cumsum(counts) - counts)[feat]
     row_idx = np.full((p, k), n, np.int32)
     values = np.zeros((p, k), np.float32)
-    for j, c in enumerate(cols):
-        row_idx[j, : len(c)] = c
-        values[j, : len(c)] = Xn[c, j]
+    row_idx[feat, rank] = rows
+    values[feat, rank] = Xt[feat, rows]
     return ByFeature(jnp.asarray(row_idx), jnp.asarray(values), n)
 
 

@@ -80,7 +80,7 @@ def _make_blocked_cd_kernel(block: int):
             u = g + b_old * h
             b_new = jnp.sign(u) * jnp.maximum(jnp.abs(u) - lam, 0.0) / h
             delta = b_new - b_old
-            g_row = pl.load(G_ref, (pl.ds(j, 1), slice(None)))  # (1, F)
+            g_row = G_ref[pl.ds(j, 1), :]                       # (1, F)
             s_ref[...] = s_ref[...] + delta * g_row
             d_ref[...] = d_ref[...] + delta * onehot
 

@@ -40,7 +40,7 @@ def _gram_cd_kernel(scal_ref, G_ref, c_ref, beta_ref, dbeta0_ref, d_ref, s_ref):
         onehot = (lane == j).astype(jnp.float32)              # (1, F)
         # scalar reads via masked reductions (lane-friendly on TPU)
         g = jnp.sum((c_ref[...] - s_ref[...]) * onehot)
-        g_row = pl.load(G_ref, (pl.ds(j, 1), slice(None)))    # (1, F)
+        g_row = G_ref[pl.ds(j, 1), :]                         # (1, F)
         h = jnp.sum(g_row * onehot) + nu                      # G[j,j] + nu
         b_old = jnp.sum((beta_ref[...] + dbeta0_ref[...] + d_ref[...]) * onehot)
         u = g + b_old * h
@@ -60,8 +60,7 @@ def gram_cd_pallas(G, c, beta, dbeta0, lam, nu, *, interpret: bool = True):
     assert G.shape == (f, f) and c.shape == (f,)
     scal = jnp.stack([jnp.asarray(lam, jnp.float32), jnp.asarray(nu, jnp.float32)])[None]
     # under shard_map(check_vma=True) the out_shape must carry the varying
-    # mesh axes; outputs vary like (c, beta, dbeta0) jointly. Older JAX has
-    # no vma typing — the compat helper degrades to a plain struct there.
+    # mesh axes; outputs vary like (c, beta, dbeta0) jointly
     out_shape = out_shape_struct((1, f), jnp.float32, operands=(c, beta, dbeta0, G))
     out = pl.pallas_call(
         _gram_cd_kernel,

@@ -8,8 +8,8 @@ d-GLMNET outer iteration needs from the examples axis (paper eq. (4)):
 
 Fusing avoids 4 separate HBM sweeps over the O(n) vectors — this matters
 because the examples axis is the big one (n up to 45M in Table 2). Tiled
-(1, BLOCK) over n with a grid; per-block partial NLL sums are reduced by
-the caller.
+(1, BLOCK) over n with a grid; per-block partial NLL sums land in lane 0
+of one (1, 128) row per block and are reduced by the wrapper.
 """
 from __future__ import annotations
 
@@ -32,7 +32,10 @@ def _logistic_stats_kernel(m_ref, y_ref, w_ref, z_ref, nll_ref):
     w = jnp.maximum(p * (1.0 - p), W_MIN)
     w_ref[...] = w
     z_ref[...] = ((y + 1.0) * 0.5 - p) / w
-    nll_ref[0, 0] = jnp.sum(jax.nn.softplus(-y * m))
+    # the block's partial NLL in lane 0 of a lane-wide (1, 128) row, a
+    # block shape the TPU accepts; the wrapper sums every row
+    lane = jax.lax.broadcasted_iota(jnp.int32, nll_ref.shape, 1)
+    nll_ref[...] = jnp.where(lane == 0, jnp.sum(jax.nn.softplus(-y * m)), 0.0)
 
 
 @partial(jax.jit, static_argnames=("block", "interpret"))
@@ -57,12 +60,12 @@ def logistic_stats_pallas(m, y, *, block: int = 4096, interpret: bool = True):
         out_specs=[
             pl.BlockSpec((1, block), lambda i: (0, i)),
             pl.BlockSpec((1, block), lambda i: (0, i)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, 128), lambda i: (0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((1, npad), jnp.float32),
             jax.ShapeDtypeStruct((1, npad), jnp.float32),
-            jax.ShapeDtypeStruct((grid[0], 1), jnp.float32),
+            jax.ShapeDtypeStruct((1, grid[0] * 128), jnp.float32),
         ],
         interpret=interpret,
     )(m[None].astype(jnp.float32), y[None].astype(jnp.float32))

@@ -33,17 +33,17 @@ from repro.kernels.logistic_stats import logistic_stats_pallas
 @lru_cache(maxsize=1)
 def _on_tpu() -> bool:
     """One backend query per process — the result cannot change under a
-    running JAX runtime, and the probe must never run inside a trace."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    running JAX runtime, and the probe must never run inside a trace. A
+    failing probe raises: it must never pass for "no TPU" and quietly
+    switch every kernel to interpret mode."""
+    return jax.default_backend() == "tpu"
 
 
 @lru_cache(maxsize=1)
 def interpret_default() -> bool:
     """The single interpret decision every kernel wrapper threads through:
-    compile natively on TPU, interpret (CPU validation) elsewhere."""
+    compile natively on TPU, interpret (CPU validation, what the tests
+    run) elsewhere."""
     return not _on_tpu()
 
 
@@ -160,7 +160,8 @@ def slab_gram(rows, vals, w, r):
             contrib = (wvf[:, None] * mk).reshape(t, k, t).sum(axis=1)
             return Gacc + contrib * va[None, :, kp], None
 
-        G, _ = jax.lax.scan(step, jnp.zeros((t, t), jnp.float32),
+        # zeros_like keeps the operands' varying mesh axes under shard_map
+        G, _ = jax.lax.scan(step, jnp.zeros_like(wv, shape=(t, t)),
                             jnp.arange(k))
     return G, jnp.sum(cva, axis=1)
 

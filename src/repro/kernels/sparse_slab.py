@@ -41,29 +41,39 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.compat import out_shape_struct
 
+# flattened slots per slab_spmv grid step: a (1024, 1) block pads to
+# (1024, 128) in VMEM, and its (1024, 256) match against one output block
+# stays at 1 MiB
+_SLOT_CHUNK = 1024
 
-def _slab_gram_kernel(rows_ref, rowsT_ref, wv_ref, vaT_ref, cva_ref,
-                      G_ref, c_ref):
-    """Refs: rows (T, K) int32; rowsT (K, T) its transpose; wv (T, K) =
-    w[row] * value (sentinel-zeroed); vaT (K, T) = value^T
-    (sentinel-zeroed); cva (T, K) = value * (w r)[row]. Outs: G (T, T),
-    c (1, T)."""
-    t, k = rows_ref.shape
-    c_ref[...] = jnp.sum(cva_ref[...], axis=1)[None, :]
+
+def _slab_gram_kernel(rowsT_ref, wvT_ref, vaT_ref, cvaT_ref, G_ref, c_ref):
+    """Refs, every per-slot operand in (K, T) layout so that each dynamic
+    slot slice falls on the sublane axis: rowsT int32 local rows; wvT =
+    w[row] * value and vaT = value (both sentinel-zeroed); cvaT = value *
+    (w r)[row]. Outs: G (T, T), c (1, T)."""
+    k, t = rowsT_ref.shape
+    c_ref[...] = jnp.sum(cvaT_ref[...], axis=0, keepdims=True)
     G_ref[...] = jnp.zeros_like(G_ref)
 
-    def pair(i, _):
-        ka = i // k
-        kb = i - ka * k
-        ra = pl.load(rows_ref, (slice(None), pl.ds(ka, 1)))    # (T, 1)
-        rb = pl.load(rowsT_ref, (pl.ds(kb, 1), slice(None)))   # (1, T)
-        wa = pl.load(wv_ref, (slice(None), pl.ds(ka, 1)))      # (T, 1)
-        vb = pl.load(vaT_ref, (pl.ds(kb, 1), slice(None)))     # (1, T)
-        eq = (ra == rb).astype(jnp.float32)                    # (T, T) match
-        G_ref[...] = G_ref[...] + (wa * eq) * vb
+    def outer(ka, _):
+        # slot ka's rows and weights as (T, 1)-wide columns, built by
+        # broadcasting the (1, T) row down the sublanes and transposing:
+        # no lane index is ever dynamic
+        ra = jnp.broadcast_to(rowsT_ref[pl.ds(ka, 1), :], (t, t)).T
+        wa = jnp.broadcast_to(wvT_ref[pl.ds(ka, 1), :], (t, t)).T
+
+        def inner(kb, _):
+            rb = rowsT_ref[pl.ds(kb, 1), :]                    # (1, T)
+            vb = vaT_ref[pl.ds(kb, 1), :]                      # (1, T)
+            eq = (ra == rb).astype(jnp.float32)                # (T, T) match
+            G_ref[...] = G_ref[...] + (wa * eq) * vb
+            return 0
+
+        jax.lax.fori_loop(0, k, inner, 0)
         return 0
 
-    jax.lax.fori_loop(0, k * k, pair, 0)
+    jax.lax.fori_loop(0, k, outer, 0)
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -81,32 +91,33 @@ def slab_gram_pallas(rows, wv, va, cva, *, interpret: bool = True):
     G, c = pl.pallas_call(
         _slab_gram_kernel,
         grid=(),
-        in_specs=[
-            pl.BlockSpec((t, k), lambda: (0, 0)),
-            pl.BlockSpec((k, t), lambda: (0, 0)),
-            pl.BlockSpec((t, k), lambda: (0, 0)),
-            pl.BlockSpec((k, t), lambda: (0, 0)),
-            pl.BlockSpec((t, k), lambda: (0, 0)),
-        ],
+        in_specs=[pl.BlockSpec((k, t), lambda: (0, 0))] * 4,
         out_specs=[
             pl.BlockSpec((t, t), lambda: (0, 0)),
             pl.BlockSpec((1, t), lambda: (0, 0)),
         ],
         out_shape=[out_g, out_c],
         interpret=interpret,
-    )(rows, rows.T, wv.astype(jnp.float32), va.astype(jnp.float32).T,
-      cva.astype(jnp.float32))
+    )(rows.T, wv.astype(jnp.float32).T, va.astype(jnp.float32).T,
+      cva.astype(jnp.float32).T)
     return G, c[0]
 
 
 def _slab_spmv_kernel(rows_ref, dv_ref, out_ref):
-    """Refs: rows (N, 1) int32 flattened slot rows; dv (N, 1) = value *
-    d[feature] (sentinel-zeroed); out (1, B), grid-tiled over examples."""
+    """Refs: rows (S, 1) int32, one chunk of the flattened slot rows; dv
+    (S, 1) = value * d[feature] (sentinel-zeroed); out (1, B). Grid
+    (example blocks, slot chunks): each example block accumulates the
+    matching slots of every chunk."""
     b = out_ref.shape[1]
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
     base = pl.program_id(0) * b
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, b), 1) + base
-    eq = (rows_ref[...] == lane).astype(jnp.float32)           # (N, B)
-    out_ref[...] = jnp.sum(dv_ref[...] * eq, axis=0)[None, :]
+    eq = (rows_ref[...] == lane).astype(jnp.float32)           # (S, B)
+    out_ref[...] = out_ref[...] + jnp.sum(dv_ref[...] * eq, axis=0)[None, :]
 
 
 @partial(jax.jit, static_argnames=("n_loc", "block", "interpret"))
@@ -115,22 +126,29 @@ def slab_spmv_pallas(rows, dv, *, n_loc: int, block: int = 256,
     """``X_F @ d`` over a slab without densify or scatter.
 
     rows (T, K) int32; dv (T, K) = values * d[:, None] with sentinel slots
-    zeroed. Returns the (n_loc,) per-example product; output rows are tiled
-    ``block`` at a time and each grid step accumulates its matching slots.
+    zeroed. Returns the (n_loc,) per-example product. Output rows are
+    tiled ``block`` at a time and the flattened slots ``_SLOT_CHUNK`` at a
+    time, so the kernel's VMEM footprint is bounded whatever T * K is.
     """
     npad = n_loc + (-n_loc) % block
     rows_col = rows.reshape(-1, 1)
     dv_col = dv.astype(jnp.float32).reshape(-1, 1)
     n_slots = rows_col.shape[0]
-    grid = (npad // block,)
+    chunk = min(_SLOT_CHUNK, n_slots + (-n_slots) % 8)
+    spad = (-n_slots) % chunk
+    if spad:
+        # padding slots carry zero value at a row no output block holds
+        rows_col = jnp.pad(rows_col, ((0, spad), (0, 0)), constant_values=-1)
+        dv_col = jnp.pad(dv_col, ((0, spad), (0, 0)))
+    grid = (npad // block, rows_col.shape[0] // chunk)
     out = pl.pallas_call(
         _slab_spmv_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((n_slots, 1), lambda i: (0, 0)),
-            pl.BlockSpec((n_slots, 1), lambda i: (0, 0)),
+            pl.BlockSpec((chunk, 1), lambda i, j: (j, 0)),
+            pl.BlockSpec((chunk, 1), lambda i, j: (j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block), lambda i: (0, i)),
+        out_specs=pl.BlockSpec((1, block), lambda i, j: (0, i)),
         out_shape=out_shape_struct((1, npad), jnp.float32,
                                    operands=(rows, dv)),
         interpret=interpret,

@@ -73,6 +73,7 @@ import numpy as np
 
 from repro.api import LogisticL1, PathResult
 from repro.checkpoint import CheckpointCorruption, verify_payload
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import observe
 from repro.configs.base import GLMConfig
 from repro.core import engine
@@ -364,6 +365,7 @@ def scenario_lost_bucket(args, mesh) -> None:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scenario", default="all",
                     choices=_SCENARIOS + ("all",))

@@ -221,9 +221,7 @@ def _lower_inner(cfg, arch, shape, shape_name, mesh, mesh_name, specs, t0,
         print(f"--- {arch} x {shape_name} x {mesh_name} "
               f"(lower {t_lower:.1f}s, compile {t_compile:.1f}s)")
         print(f"    memory_analysis: {mem}")
-        from repro.compat import cost_analysis
-
-        ca = cost_analysis(compiled)
+        ca = compiled.cost_analysis()
         print(f"    cost_analysis: flops={ca.get('flops', 0):.3e} "
               f"bytes={ca.get('bytes accessed', 0):.3e}")
         print(f"    roofline: t_comp={roof.t_compute*1e3:.2f}ms "

@@ -118,9 +118,7 @@ class Roofline:
 
 def analyze(compiled, *, arch: str, shape: str, mesh_name: str, chips: int,
             model_flops: float = 0.0) -> Roofline:
-    from repro.compat import cost_analysis
-
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     # cost_analysis reports the per-device (post-SPMD-partitioning) module;
     # scale FLOPs to the global total (uniform across devices). bytes and
     # collective bytes stay per-device to match per-chip bandwidth terms.

@@ -59,6 +59,7 @@ import numpy as np
 from repro.api import LogisticL1, PathResult, SlabDesign, ShardedDesign
 from repro.configs.base import GLMConfig
 from repro.data.synthetic import make_glm_dataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import observe, trace as obs_trace
 from repro.serve import PathScorer, PathStore, RequestBatcher, hash_token
 
@@ -124,7 +125,24 @@ def smoke_check(est, store, scorer, batch, n_live: int, path) -> None:
           f"{len(path)} lambdas")
 
 
+def hot_swap_check(store, scorer, batch, lams, path) -> None:
+    """Publish a truncated path mid-traffic: the next batch must score
+    against exactly the new version, without dropping a row."""
+    sub = PathResult(lambdas=path.lambdas[:2], betas=path.betas[:2],
+                     nnz=path.nnz[:2], f=path.f[:2],
+                     n_iters=path.n_iters[:2], metrics=path.metrics[:2],
+                     screen=path.screen[:2])
+    v_before = scorer.score(batch, lams)[1]
+    store.swap(sub)
+    got, v_after = scorer.score(batch, lams)
+    if v_after != v_before + 1 or len(got) != batch.n_live:
+        raise SystemExit("FAIL: hot-swap version bookkeeping broken")
+    print(f"# smoke: hot-swap v{v_before} -> v{v_after} served "
+          f"{len(got)} scores without dropping the batch")
+
+
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small shapes + bit-equality and hot-swap "
@@ -231,19 +249,7 @@ def _run(args, mesh):
     if args.smoke:
         smoke_check(est if args.load_path is None else LogisticL1(mesh=mesh),
                     store, scorer, warm_batch, warm_batch.n_live, path)
-        # hot-swap: publish a truncated path mid-traffic; batches must
-        # score against exactly one version each
-        sub = PathResult(lambdas=path.lambdas[:2], betas=path.betas[:2],
-                         nnz=path.nnz[:2], f=path.f[:2],
-                         n_iters=path.n_iters[:2], metrics=path.metrics[:2],
-                         screen=path.screen[:2])
-        v_before = scorer.score(warm_batch, warm_lams)[1]
-        store.swap(sub)
-        got, v_after = scorer.score(warm_batch, warm_lams)
-        if v_after != v_before + 1 or len(got) != warm_batch.n_live:
-            raise SystemExit("FAIL: hot-swap version bookkeeping broken")
-        print(f"# smoke: hot-swap v{v_before} -> v{v_after} served "
-              f"{len(got)} scores without dropping the batch")
+        hot_swap_check(store, scorer, warm_batch, warm_lams, path)
         print("SERVE SMOKE OK")
 
 

@@ -225,8 +225,6 @@ def flash_decode_seq_sharded(q, ck, cv, cache_index, *, scale, window=0,
     from jax.sharding import PartitionSpec as P
     from functools import partial as _partial
 
-    from repro.compat import shard_map as _shard_map
-
     b, _, h, dh = q.shape
     s = ck.shape[1]
     shards = mesh.shape[model_axis]
@@ -241,7 +239,7 @@ def flash_decode_seq_sharded(q, ck, cv, cache_index, *, scale, window=0,
     bax = daxes if (daxes and b % dsz == 0) else None
 
     @_partial(
-        _shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(bax), P(bax, model_axis, None, None),
                   P(bax, model_axis, None, None), P(), P()),
         out_specs=P(bax),

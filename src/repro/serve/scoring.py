@@ -63,7 +63,6 @@ def make_path_margins(mesh, n_loc: int, model_axis: str = "model"):
     ``PathStore.swap`` can actually release the old coefficients."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
     from repro.core.distributed import _data_axes
 
     daxes = _data_axes(mesh)
@@ -71,7 +70,7 @@ def make_path_margins(mesh, n_loc: int, model_axis: str = "model"):
 
     @jax.jit
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(model_axis, daxes, None), P(model_axis, daxes, None),
                   dspec, P(None, model_axis)),

@@ -198,8 +198,10 @@ def test_sparse_fit_densify_fallback_equivalence():
         from repro.data.synthetic import make_glm_dataset
         from repro.launch.mesh import make_dev_mesh
 
+        # K per feature per data shard ~ Poisson(819 * 0.0025 = 2): its
+        # max stays far below the slab-regime bound sqrt(819 / 8) ~ 10
         cfg = GLMConfig(name='fb', num_examples=2048, num_features=64,
-                        density=0.005)
+                        density=0.0025)
         ds = make_glm_dataset(cfg, jax.random.key(8))
         X, y = ds.X_train, ds.y_train
         n = (X.shape[0] // 2) * 2

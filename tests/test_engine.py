@@ -80,7 +80,7 @@ def test_solver_outer_loop_is_single_while(small_glm):
     m = margins(X, beta)
     solve = _solver_for(opts)
     jaxpr = jax.make_jaxpr(solve)(X, y, beta, m, lam).jaxpr
-    if [e.primitive.name for e in jaxpr.eqns] == ["pjit"]:
+    if [e.primitive.name for e in jaxpr.eqns] == ["jit"]:
         jaxpr = jaxpr.eqns[0].params["jaxpr"].jaxpr  # descend into the jit
     prims = [eqn.primitive.name for eqn in jaxpr.eqns]
     assert prims.count("while") == 1, prims

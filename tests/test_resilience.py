@@ -369,6 +369,8 @@ def test_serve_latency_injection_is_scoped():
 def test_chaos_smoke_on_mesh():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
+    # the launcher turns the persistent compile cache on unless told not to
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     r = subprocess.run(
         [sys.executable, "-m", "repro.launch.chaos_glm", "--smoke",
          "--mesh", "2x4"],
