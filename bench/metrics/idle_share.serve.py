@@ -1,0 +1,7 @@
+"""Share of the traced serving window with no operation on the device,
+in %."""
+
+
+def read(facts):
+    t = facts["trace"]
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
