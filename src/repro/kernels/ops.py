@@ -28,6 +28,11 @@ import jax.numpy as jnp
 
 from repro.kernels.gram_cd import gram_cd_pallas
 from repro.kernels.logistic_stats import logistic_stats_pallas
+from repro.obs import trace as obs_trace
+
+# every backend compile or persistent-cache load becomes a ``compile`` span
+# of the active repro.obs tracer, under the span whose call caused it
+jax.monitoring.register_event_duration_secs_listener(obs_trace.on_compile)
 
 
 @lru_cache(maxsize=1)
@@ -208,9 +213,10 @@ def slab_path_spmv(rows, vals, lam_idx, betas, *, n_loc: int):
     safe = jnp.minimum(rows, n_loc)
     # sentinel rows read lam_idx[0] through the clamp; their dv is zeroed
     # by the validity mask so the read value never matters
-    li = jnp.take(lam_idx, jnp.where(valid, rows, 0))            # (T, K)
-    feat = jnp.arange(rows.shape[0], dtype=jnp.int32)[:, None]
-    bsel = betas.astype(jnp.float32)[li, feat]                   # (T, K)
+    with jax.named_scope("path_gather"):
+        li = jnp.take(lam_idx, jnp.where(valid, rows, 0))        # (T, K)
+        feat = jnp.arange(rows.shape[0], dtype=jnp.int32)[:, None]
+        bsel = betas.astype(jnp.float32)[li, feat]               # (T, K)
     dv = jnp.where(valid, vals, 0.0).astype(jnp.float32) * bsel
     if _on_tpu():
         from repro.kernels.sparse_slab import slab_spmv_pallas

@@ -29,6 +29,10 @@ gathered-weight side, so even adversarial padding values cannot leak row
 
 Validated on CPU with ``interpret=True`` against ``ref.slab_gram_ref`` /
 ``ref.slab_spmv_ref`` (densify-based oracles).
+
+Each ``pallas_call`` carries its wrapper's name: it names the kernel's
+HLO instruction (``slab_spmv_pallas.1``), which is how a device trace
+shows it, and stays put when the code around it is refactored.
 """
 from __future__ import annotations
 
@@ -98,6 +102,7 @@ def slab_gram_pallas(rows, wv, va, cva, *, interpret: bool = True):
         ],
         out_shape=[out_g, out_c],
         interpret=interpret,
+        name="slab_gram_pallas",
     )(rows.T, wv.astype(jnp.float32).T, va.astype(jnp.float32).T,
       cva.astype(jnp.float32).T)
     return G, c[0]
@@ -152,5 +157,6 @@ def slab_spmv_pallas(rows, dv, *, n_loc: int, block: int = 256,
         out_shape=out_shape_struct((1, npad), jnp.float32,
                                    operands=(rows, dv)),
         interpret=interpret,
+        name="slab_spmv_pallas",
     )(rows_col, dv_col)
     return out[0, :n_loc]

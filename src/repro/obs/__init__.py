@@ -43,7 +43,7 @@ from repro.obs.registry import (
     histogram,
     use_registry,
 )
-from repro.obs.trace import Tracer, event, get_tracer, span, use_tracer
+from repro.obs.trace import Tracer, get_tracer, span, use_tracer
 from repro.obs.export import (
     chrome_trace,
     summarize,
@@ -61,7 +61,6 @@ __all__ = [
     "Tracer",
     "chrome_trace",
     "counter",
-    "event",
     "gauge",
     "get_registry",
     "get_tracer",

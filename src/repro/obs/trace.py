@@ -15,13 +15,23 @@ The span tree mirrors the solver and serve loops::
                         > restricted_solve > bucket_stream
                         > kkt_check        > bucket_stream
                         > point_finish
-    serve > drain
+    serve > drain  > pack
           > encode        (from submit; parents under serve when nested)
-          > score
+          > score  > put
+                   > launch > compile
+                   > fetch
           > swap
 
 Nesting is tracked per-thread: each thread keeps its own span stack, so
 a serve thread and a solver thread never corrupt each other's parents.
+
+Where JAX is already loaded, each span of an active tracer also opens a
+`jax.profiler.TraceAnnotation` of its name on its thread (found through
+`sys.modules`, never imported here): under a profiler session the spans
+land in its trace, on its clock, beside the device's operations; without
+one the annotation costs about a microsecond. A `compile` span is
+recorded by `on_compile`, a JAX duration listener that a module which
+imports JAX registers, from the time JAX measured for the compile.
 
 With no active tracer, `span()` returns a shared `_NULL_SPAN` singleton
 whose `__enter__`/`__exit__`/`set` are no-ops.
@@ -29,18 +39,36 @@ whose `__enter__`/`__exit__`/`set` are no-ops.
 from __future__ import annotations
 
 import itertools
+import sys
 import threading
 import time
 from contextlib import contextmanager
 from typing import Dict, Iterator, List, Optional
 
-__all__ = ["Tracer", "event", "get_tracer", "span", "use_tracer"]
+__all__ = ["COMPILE_EVENT", "Tracer", "get_tracer", "on_compile", "span",
+           "use_tracer"]
+
+#: JAX's monitoring event timing one backend compile or persistent-cache
+#: load (``jax._src.dispatch.BACKEND_COMPILE_EVENT``)
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation`` of ``name``, or None
+    where JAX is not loaded."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return None
+    ann = profiler.TraceAnnotation(name)
+    ann.__enter__()
+    return ann
 
 
 class _Span:
     """Context manager recording one timed span on `tracer`."""
 
-    __slots__ = ("_tracer", "name", "args", "sid", "parent", "_t0", "_tid")
+    __slots__ = ("_tracer", "name", "args", "sid", "parent", "_t0", "_tid",
+                 "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict[str, object]):
         self._tracer = tracer
@@ -50,6 +78,7 @@ class _Span:
         self.parent: Optional[int] = None
         self._t0 = 0.0
         self._tid = 0
+        self._ann = None
 
     def set(self, **kw: object) -> "_Span":
         """Attach result metadata (nnz, status, ...) to the open span."""
@@ -62,11 +91,14 @@ class _Span:
         self.parent = stack[-1].sid if stack else None
         self._tid = tracer._tid()
         stack.append(self)
+        self._ann = _annotation(self.name)
         self._t0 = tracer.clock()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1 = self._tracer.clock()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         stack = self._tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -138,20 +170,14 @@ class Tracer:
     def span(self, name: str, **args: object) -> _Span:
         return _Span(self, name, args)
 
-    def event(self, name: str, **args: object) -> None:
-        """Record an instantaneous (zero-duration) marker."""
+    def record(self, name: str, dur: float, **args: object) -> None:
+        """Record a span of ``dur`` seconds that ends now, on the calling
+        thread, under the span open there (a compile JAX timed)."""
+        t1 = self.clock()
+        sp = _Span(self, name, args)
         stack = self._stack()
-        rec = {
-            "name": name,
-            "ts": self.clock() - self.t0,
-            "dur": 0.0,
-            "tid": self._tid(),
-            "sid": next(self._sid),
-            "parent": stack[-1].sid if stack else None,
-            "args": args,
-        }
-        with self._lock:
-            self.spans.append(rec)
+        sp.parent = stack[-1].sid if stack else None
+        self._record(sp, t1 - dur, dur, self._tid())
 
     def wall_s(self) -> float:
         """Wall time covered so far: last span end (or now if none)."""
@@ -174,10 +200,13 @@ def span(name: str, **args: object):
     return _NULL_SPAN if tracer is None else tracer.span(name, **args)
 
 
-def event(name: str, **args: object) -> None:
+def on_compile(event: str, duration: float, **kw: object) -> None:
+    """JAX duration listener: a backend compile or persistent-cache load
+    (``COMPILE_EVENT``) becomes a ``compile`` span of the active tracer,
+    on the thread that compiled and so under the span that caused it."""
     tracer = _ACTIVE
-    if tracer is not None:
-        tracer.event(name, **args)
+    if tracer is not None and event == COMPILE_EVENT:
+        tracer.record("compile", duration, fun=kw.get("fun_name"))
 
 
 @contextmanager

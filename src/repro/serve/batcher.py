@@ -23,9 +23,16 @@ Observability rides the same path without changing it: every request
 records its submit timestamp, and the serve loop's :meth:`RequestBatcher.
 mark_scored` call (right after the scorer hands back host scores) feeds
 a submit->score ``serve.latency_s`` histogram on the active ``repro.obs``
-registry, with the live queue depth exported as a gauge. The legacy
+registry, with the live queue depth a lazy callback. The legacy
 :attr:`RequestBatcher.stats` dict is bit-identical with or without a
-registry — it is mirrored read-only, never rewritten.
+registry — it is mirrored read-only, never rewritten. Under a tracer,
+``encode`` carries the request's submit sequence number (``req``);
+``drain`` carries the batch's sequence number (``batch``), the first and
+last ``req`` it took (the queue is FIFO, so with one submitting thread
+it took every queued, unexpired request numbered in between), how many
+it took (``drained``) and their summed queue wait on the batcher's clock
+(``wait_s``); its child ``pack`` and the scorer's spans carry the same
+``batch``.
 
 Lambdas stay raw floats until scoring: ``PathScorer`` resolves them
 against the snapshot it scores with, so a hot-swap that re-grids the path
@@ -33,6 +40,7 @@ re-resolves naturally instead of serving stale indices.
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from typing import List, Optional, Tuple
@@ -116,13 +124,16 @@ class RequestBatcher:
         self.default_ttl_s = default_ttl_s
         self.clock = clock
         self._lock = threading.Lock()
-        # (encoded, lam, expiry-on-self.clock-or-None, submit-ts) per
-        # pending request; the submit timestamp feeds the submit->score
-        # latency histogram and is never part of the legacy stats surface
+        # (encoded, lam, expiry-on-self.clock-or-None, submit-ts, req)
+        # per pending request; the submit timestamp feeds the submit->score
+        # latency histogram and the queue wait, the submit sequence number
+        # the trace, and neither is part of the legacy stats surface
         self._pending: List[
             Tuple[Tuple[np.ndarray, np.ndarray], float, Optional[float],
-                  float]
+                  float, int]
         ] = []
+        self._reqs = itertools.count()
+        self._batches = itertools.count()
         self._stats = {"submitted": 0, "rejected_overload": 0,
                        "rejected_invalid": 0, "shed_expired": 0,
                        "drained": 0}
@@ -141,8 +152,9 @@ class RequestBatcher:
         InvalidRequest` on garbage input and :class:`Overloaded` when the
         queue is at ``max_pending`` — both counted before raising.
         """
+        req = next(self._reqs)
         try:
-            with obs_trace.span("encode"):
+            with obs_trace.span("encode", req=req):
                 enc = encode_request(request, self.p)
             idx = enc[0]
             if idx.size and (int(idx.min()) < 0 or int(idx.max()) >= self.p):
@@ -163,10 +175,8 @@ class RequestBatcher:
                     f"pending queue full ({self.max_pending} requests): "
                     f"drain is not keeping up — shed and retry with backoff"
                 )
-            self._pending.append((enc, float(lam), expiry, now))
+            self._pending.append((enc, float(lam), expiry, now, req))
             self._stats["submitted"] += 1
-            depth = len(self._pending)
-        obs_registry.gauge("serve.queue_depth").set(depth)
 
     def __len__(self) -> int:
         with self._lock:
@@ -198,15 +208,19 @@ class RequestBatcher:
                                        live[self.max_batch:])
                 self._stats["drained"] += len(take)
                 self._last_drained_ts = [e[3] for e in take]
-                depth = len(self._pending)
-            obs_registry.gauge("serve.queue_depth").set(depth)
+                batch_id = next(self._batches)
             encoded = [e[0] for e in take]
             lams = np.asarray([e[1] for e in take], np.float64)
             cap = batch_capacity(max(len(encoded), 1), b_max=self.max_batch)
             cap += (-cap) % max(self.dp, 1)
-            batch = pack_requests(encoded, self.p, batch_cap=cap, dp=self.dp,
-                                  pad_p_to=self.pad_p_to, k_min=self.k_min)
-            sp.set(drained=len(take))
+            with obs_trace.span("pack", batch=batch_id):
+                batch = pack_requests(encoded, self.p, batch_cap=cap,
+                                      dp=self.dp, pad_p_to=self.pad_p_to,
+                                      k_min=self.k_min, batch_id=batch_id)
+            sp.set(batch=batch_id, drained=len(take),
+                   wait_s=sum((now - e[3] for e in take), 0.0))
+            if take:
+                sp.set(req=[take[0][4], take[-1][4]])
         return batch, lams
 
     def mark_scored(self) -> int:

@@ -89,7 +89,9 @@ class PackedBatch:
     — exactly the operand layout of ``core.distributed.make_slab_margins``
     and the serve scoring steps. Rows >= ``n_live`` are padding (all-
     sentinel; they score 0 and are trimmed before scores leave the
-    scorer).
+    scorer). ``batch_id`` is the draining batcher's sequence number, the
+    ``batch`` argument of every trace span the batch passes through (-1
+    for a batch packed outside a batcher).
     """
 
     row_idx: np.ndarray          # (p_pad, DP, K) int32
@@ -97,6 +99,7 @@ class PackedBatch:
     n_live: int                  # real requests in the batch
     batch_cap: int               # padded batch extent (= DP * n_loc)
     p: int                       # original (unpadded) feature count
+    batch_id: int = -1
 
     @property
     def dp(self) -> int:
@@ -119,6 +122,7 @@ def pack_requests(
     dp: int = 1,
     pad_p_to: int = 1,
     k_min: int = 8,
+    batch_id: int = -1,
 ) -> PackedBatch:
     """Pack encoded requests into a :class:`PackedBatch`.
 
@@ -126,9 +130,9 @@ def pack_requests(
     padded request extent; ``pad_p_to`` rounds the feature axis up (mesh
     stores pass ``model_dim * tile`` so the slab partition lines up with
     the P(model)-sharded coefficient stack); ``k_min`` floors the
-    power-of-two K class. Slabs are front-packed (live slots first, rows
-    ascending within a feature) — the same invariant the training layout
-    guarantees.
+    power-of-two K class; ``batch_id`` tags the batch for tracing. Slabs
+    are front-packed (live slots first, rows ascending within a feature)
+    — the same invariant the training layout guarantees.
     """
     b = len(encoded)
     if batch_cap is None:
@@ -171,4 +175,4 @@ def pack_requests(
     row_idx[g // dp, g % dp, rank] = loc[order]
     values[g // dp, g % dp, rank] = vals[order]
     return PackedBatch(row_idx=row_idx, values=values, n_live=b,
-                       batch_cap=batch_cap, p=p)
+                       batch_cap=batch_cap, p=p, batch_id=batch_id)

@@ -132,9 +132,16 @@ class PathScorer:
         :class:`NonFiniteScores` escape. Requests never see poison.
 
         The ``score`` span closes at the existing ``np.asarray`` host
-        sync on the scores — tracing adds no extra device->host hop.
+        sync on the scores — tracing adds no extra device->host hop. Its
+        children, each tagged with the batch's ``batch_id``, split it:
+        ``put`` places the slabs and ``lam_idx`` on the device (``bytes``
+        placed), ``launch`` enqueues the scoring program (and holds any
+        ``compile``), ``fetch`` is that ``np.asarray``: the device's run
+        and the copy back. Its self time is the snapshot read, lambda
+        resolution and the finite check.
         """
-        with obs_trace.span("score", rows=int(batch.n_live)) as sp:
+        with obs_trace.span("score", rows=int(batch.n_live),
+                            batch=batch.batch_id) as sp:
             scores, version = self._score(batch, lams)
             sp.set(version=version)
             return scores, version
@@ -160,7 +167,9 @@ class PathScorer:
             if batch.n_live:
                 lam_idx[:batch.n_live] = snap.indices_of(lams)
             serve_delay()                   # chaos latency injection point
-            scores = np.asarray(self._dispatch(batch, lam_idx, snap))
+            out = self._dispatch(batch, lam_idx, snap)
+            with obs_trace.span("fetch", batch=batch.batch_id):
+                scores = np.asarray(out)
             live = scores[:batch.n_live]
             if np.all(np.isfinite(live)):
                 return live, snap.version
@@ -174,15 +183,26 @@ class PathScorer:
 
     def _dispatch(self, batch: PackedBatch, lam_idx: np.ndarray,
                   snap: StoreSnapshot):
+        """Place the batch on the device and enqueue its scoring program;
+        returns the device scores."""
+        with obs_trace.span("put", batch=batch.batch_id) as sp:
+            fn, args = self._place(batch, lam_idx)
+            sp.set(bytes=batch.row_idx.nbytes + batch.values.nbytes
+                   + lam_idx.nbytes)
+        with obs_trace.span("launch", batch=batch.batch_id):
+            return fn(*args, snap.betas)
+
+    def _place(self, batch: PackedBatch, lam_idx: np.ndarray):
+        """``(program, device operands)`` of the batch's scoring step."""
         mesh = self.store.mesh
         if mesh is None:
             if batch.dp != 1:
                 raise ValueError(
                     f"local scoring needs dp=1 slabs, got dp={batch.dp}")
-            return _score_local(
+            return partial(_score_local, n_loc=batch.batch_cap), (
                 jnp.asarray(batch.row_idx[:, 0, :]),
                 jnp.asarray(batch.values[:, 0, :]),
-                jnp.asarray(lam_idx), snap.betas, n_loc=batch.batch_cap)
+                jnp.asarray(lam_idx))
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from repro.core.distributed import _data_axes, _data_extent
@@ -199,7 +219,5 @@ class PathScorer:
         from repro.data.residency import put_slab
 
         rows_dev, vals_dev = put_slab(batch.row_idx, batch.values, slab_sh)
-        return fn(
-            rows_dev, vals_dev,
-            jax.device_put(lam_idx, NamedSharding(mesh, P(daxes))),
-            snap.betas)
+        return fn, (rows_dev, vals_dev,
+                    jax.device_put(lam_idx, NamedSharding(mesh, P(daxes))))

@@ -1,9 +1,10 @@
 """Tier-1 tests for ``repro.obs`` — registry, spans, exporters, report.
 
 The fast lane runs this file: everything here is stdlib + tiny numpy
-shapes except the two integration tests at the bottom, which trace one
-tiny real path solve and one serve drain->score round to pin the wiring
-(span tree shape, per-phase accounting, legacy-counter bit-identity).
+shapes except the integration tests at the bottom, which trace one tiny
+real path solve and serve drain->score rounds to pin the wiring (span
+tree shape, per-phase accounting, legacy-counter bit-identity, batch
+ids, bytes, queue waits, compiles, the profiler's copy of the spans).
 """
 import json
 import threading
@@ -113,7 +114,7 @@ def test_disabled_helpers_return_null_singletons():
     obs_registry.histogram("x").observe(0.1)
     with obs_trace.span("x") as sp:
         sp.set(k=1)
-    obs_trace.event("x")
+    obs_trace.on_compile(obs_trace.COMPILE_EVENT, 0.5, fun_name="jit(f)")
 
 
 def test_use_registry_is_reentrant():
@@ -280,18 +281,31 @@ def _drive_batcher(batcher):
     return dict(batcher.stats)
 
 
-def test_batcher_stats_bit_identical_with_and_without_obs():
+def test_batcher_stats_bit_identical_with_and_without_obs(monkeypatch):
     from repro.serve import RequestBatcher
 
     def build():
         return RequestBatcher(16, max_batch=8, max_pending=8)
 
+    # untraced, nothing records a span or opens a profiler annotation
+    recorded = []
+    monkeypatch.setattr(Tracer, "_record",
+                        lambda self, *a: recorded.append(a))
+    monkeypatch.setattr(obs_trace, "_annotation",
+                        lambda name: recorded.append(name))
     stats_off = _drive_batcher(build())
+    assert recorded == []
+    monkeypatch.undo()
     with observe() as obs:
         stats_on = _drive_batcher(build())
         mirrored = obs.registry.collect()["callbacks"]["serve.batcher"]
+        collected = obs.registry.collect()
     assert stats_on == stats_off                 # legacy dict untouched
     assert mirrored == stats_on                  # registry mirrors it
+    assert collected["callbacks"]["serve.queue"] == {"depth": 0}
+    assert "serve.queue_depth" not in collected["gauges"]
+    assert {r["name"] for r in obs.tracer.spans} == {"encode", "drain",
+                                                     "pack"}
 
 
 def test_traced_tiny_path_phases_add_up():
@@ -319,3 +333,151 @@ def test_traced_tiny_path_phases_add_up():
     # untraced rerun is bit-identical (tracing changed no math)
     path2 = est.path(DenseDesign(X), y, path_len=3)
     assert np.array_equal(np.asarray(path.betas), np.asarray(path2.betas))
+
+
+# ---------------------------------------------------------------------------
+# serve spans: one batch id from drain to fetch, bytes, waits, compiles,
+# and the profiler's trace
+# ---------------------------------------------------------------------------
+
+def _serve_round(p):
+    """A store and batcher at width ``p`` (a scoring shape no other test
+    of this file compiles), and ``round_(reqs)``, which submits
+    ``reqs``, drains once, scores the batch and returns it."""
+    from repro.api.types import PathResult
+    from repro.serve import PathScorer, PathStore, RequestBatcher
+
+    path = PathResult(
+        lambdas=np.asarray([1.0, 0.5]),
+        betas=jnp.asarray(np.random.default_rng(p).normal(size=(2, p)),
+                          jnp.float32),
+        nnz=np.asarray([p, p]), f=np.zeros(2), n_iters=np.ones(2, np.int64),
+        metrics=[{}, {}], screen=[{}, {}])
+    batcher = RequestBatcher(p, max_batch=8)
+    scorer = PathScorer(PathStore(path))
+
+    def round_(reqs):
+        for r in reqs:
+            batcher.submit(r, 0.5)
+        batch, lams = batcher.drain()
+        scorer.score(batch, lams)
+        return batch
+
+    return round_
+
+
+def _by_name(spans):
+    out = {}
+    for r in spans:
+        out.setdefault(r["name"], []).append(r)
+    return out
+
+
+def test_serve_spans_share_one_batch_id():
+    round_ = _serve_round(41)
+    tr = Tracer()
+    with obs_trace.use_tracer(tr):
+        round_([{"a": 1.0}, {"b": 2.0, "c": 1.0}, {"d": 0.5}])
+    by = _by_name(tr.spans)
+    (drain,), (pack,), (score,) = by["drain"], by["pack"], by["score"]
+    assert pack["parent"] == drain["sid"]
+    kids = [r for r in tr.spans if r["parent"] == score["sid"]]
+    assert [r["name"] for r in sorted(kids, key=lambda r: r["ts"])] == [
+        "put", "launch", "fetch"]
+    bid = drain["args"]["batch"]
+    assert all(r["args"]["batch"] == bid for r in [pack, score] + kids)
+    lo, hi = drain["args"]["req"]
+    assert drain["args"]["drained"] == 3 == hi - lo + 1
+    assert sorted(r["args"]["req"] for r in by["encode"]) == [lo, lo + 1,
+                                                              hi]
+
+
+def test_put_bytes_are_the_arrays_placed():
+    round_ = _serve_round(43)
+    tr = Tracer()
+    with obs_trace.use_tracer(tr):
+        batch = round_([{"a": 1.0}, {"b": 2.0}])
+    (put,) = _by_name(tr.spans)["put"]
+    lam_idx_nbytes = batch.batch_cap * np.dtype(np.int32).itemsize
+    assert put["args"]["bytes"] == (batch.row_idx.nbytes
+                                    + batch.values.nbytes + lam_idx_nbytes)
+
+
+def test_drain_wait_is_the_summed_queue_wait():
+    now = [0.0]
+    tr = Tracer()
+    with obs_trace.use_tracer(tr):
+        from repro.serve import RequestBatcher
+
+        batcher = RequestBatcher(16, max_batch=8, clock=lambda: now[0])
+        for t in (1.0, 2.0, 3.5):
+            now[0] = t
+            batcher.submit({f"t{t}": 1.0}, 0.5)
+        now[0] = 10.0
+        batcher.drain()
+        batcher.drain()                          # empty: waits nothing
+    full, empty = _by_name(tr.spans)["drain"]
+    assert full["args"]["wait_s"] == (10.0 - 1.0) + (10.0 - 2.0) + (
+        10.0 - 3.5)
+    assert empty["args"]["drained"] == 0 and empty["args"]["wait_s"] == 0.0
+    assert "req" not in empty["args"]
+
+
+def test_first_score_of_a_shape_records_its_compile_under_launch():
+    round_ = _serve_round(47)
+    tr = Tracer()
+    with obs_trace.use_tracer(tr):
+        round_([{"a": 1.0}])
+    by = _by_name(tr.spans)
+    (launch,) = by["launch"]
+    assert by["compile"], "the new scoring shape compiled no program"
+    for c in by["compile"]:
+        assert c["parent"] == launch["sid"] and c["dur"] > 0
+        assert launch["ts"] <= c["ts"] and (
+            c["ts"] + c["dur"] <= launch["ts"] + launch["dur"])
+    tr2 = Tracer()
+    with obs_trace.use_tracer(tr2):
+        round_([{"b": 1.0}])
+    names = {r["name"] for r in tr2.spans}
+    assert "launch" in names and "compile" not in names
+
+
+def test_spans_land_in_the_profiler_trace_on_its_clock(tmp_path):
+    import time
+
+    import jax
+    from jax.profiler import ProfileData
+
+    anchor = "obs_test_anchor"
+    round_ = _serve_round(51)
+    tr = Tracer()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with jax.profiler.TraceAnnotation(anchor):
+            anchor_perf = time.perf_counter()
+        with obs_trace.use_tracer(tr):
+            round_([{"a": 1.0}, {"b": 2.0}])
+    finally:
+        jax.profiler.stop_trace()
+    (xplane,) = tmp_path.rglob("*.xplane.pb")
+    pd = ProfileData.from_file(str(xplane))
+    host = {}
+    for plane in pd.planes:
+        for line in plane.lines:
+            for ev in line.events:
+                host.setdefault(ev.name, []).append(int(ev.start_ns))
+    (a_ns,) = host[anchor]
+    by = _by_name(tr.spans)
+    assert {"encode", "drain", "pack", "score", "put", "launch",
+            "fetch"} <= set(by)
+    for name, recs in by.items():
+        if name == "compile":                    # recorded after the fact
+            continue
+        starts = sorted(host.get(name, []))
+        assert len(starts) == len(recs), name
+        for rec, ns in zip(sorted(recs, key=lambda r: r["ts"]), starts):
+            perf = tr.t0 + rec["ts"]
+            want = a_ns + (perf - anchor_perf) * 1e9
+            assert abs(ns - want) < 1e6, (name, ns - want)
