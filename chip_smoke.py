@@ -23,9 +23,11 @@ One chip (default):
   the same lambda on the same chip, within ``OBJ_RTOL``.
 * **serve** -- the fitted path in a ``PathStore``; a few hundred hashed
   requests spread over every lambda through ``RequestBatcher`` and
-  ``PathScorer`` (``slab_path_spmv`` on the native ``slab_spmv`` Pallas
-  kernel); served scores bit-equal to ``decision_function`` at every
-  lambda; one hot swap.
+  ``PathScorer`` (``entry_path_spmv`` over each batch's entry list, plain
+  XLA); served scores against ``decision_function`` (the ``slab_spmv``
+  Pallas kernel on the batch's slabs) at every lambda, within
+  ``SCORE_GAP`` of sum |beta v| per row, since the two programs sum a
+  row's terms in their own orders; one hot swap.
 * **sparse** -- a webspam-density twin through
   ``ShardedDesign(SlabDesign.from_dense(X), mesh)`` on a 1x1 data x model
   mesh of the real device: the path on which ``slab_gram`` and
@@ -230,6 +232,15 @@ def check_agree(X, y, path_a, path_b, label: str, *,
             log(msg)
 
 
+def assert_entry_program(fn, *args) -> None:
+    """The compiled scoring program is the entry-list program: no Mosaic
+    slab kernel."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    if "tpu_custom_call" in text:
+        fail(f"{getattr(fn, '__name__', fn)} compiled with a Mosaic "
+             f"kernel: not the entry-list program")
+
+
 def assert_native(fn, *args, **static) -> None:
     """The compiled program holds a Mosaic kernel: no interpret mode, no
     jnp fallback."""
@@ -279,7 +290,8 @@ def reference_phase(X, y, opts, path) -> None:
 
 
 def serve_phase(est, path, p: int, seed: int) -> None:
-    """Hashed traffic over every lambda, bit-equality and one hot swap."""
+    """Hashed traffic over every lambda, served-vs-reference agreement and
+    one hot swap."""
     store = PathStore(path)
     scorer = PathScorer(store)
     batcher = RequestBatcher(p, max_batch=SERVE_BATCH)
@@ -299,18 +311,22 @@ def serve_phase(est, path, p: int, seed: int) -> None:
             fail(f"serve: batch {b} returned {len(scores)} scores "
                  f"for {batch.n_live} requests, or non-finite ones")
         hit |= set(store.snapshot.indices_of(blams).tolist())
-        first = batch.row_idx.shape not in shapes   # new shape: compiles
-        shapes.add(batch.row_idx.shape)
+        shape = (batch.batch_cap, batch.entry_row.shape[0])
+        first = shape not in shapes                 # new shape: compiles
+        shapes.add(shape)
         log(f"# serve: batch {b} ({'first call' if first else 'warm'}): "
-            f"{len(scores)} scores in {dt * 1e3:.3f} ms, slab "
-            f"{batch.row_idx.shape}, version {version}")
+            f"{len(scores)} scores in {dt * 1e3:.3f} ms, "
+            f"{batch.n_entries} entries in {shape[1]} slots, version "
+            f"{version}")
     if hit != set(range(len(path))):
         fail(f"serve: traffic reached lambdas {sorted(hit)} of {len(path)}")
-    assert_native(_score_local, jnp.asarray(batch.row_idx[:, 0, :]),
-                  jnp.asarray(batch.values[:, 0, :]),
-                  jnp.zeros(batch.batch_cap, jnp.int32),
-                  store.snapshot.betas, n_loc=batch.batch_cap)
-    log("# serve: scoring program runs the native slab_spmv kernel")
+    assert_entry_program(_score_local, jnp.asarray(batch.entry_row),
+                         jnp.asarray(batch.entry_feat),
+                         jnp.asarray(batch.entry_val),
+                         jnp.zeros(batch.batch_cap, jnp.int32),
+                         store.snapshot.betas)
+    log("# serve: scoring program is the entry-list program, no slab "
+        "kernel")
     smoke_check(est, store, scorer, batch, batch.n_live, path)
     hot_swap_check(store, scorer, batch, blams, path)
 

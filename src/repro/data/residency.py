@@ -25,7 +25,8 @@ makes streamed solves bit-identical to resident ones.
 This module is also the **single home** of slab-bucket
 ``jax.device_put`` (enforced by the ``bucket-residency`` analysis rule):
 transient slab placements outside the managed work buckets (restricted-
-solve operands, serve request slabs) go through :func:`put_slab`.
+solve operands, serve request slabs) go through :func:`put_slab`, and a
+serve batch's flat entry list through :func:`put_entries`.
 
 Failure model: every put attempt consults
 ``repro.resilience.take_prefetch_failure`` and runs under
@@ -61,6 +62,14 @@ def put_slab(row_idx, values, sharding=None):
     if sharding is None:
         return jax.device_put(row_idx), jax.device_put(values)
     return jax.device_put(row_idx, sharding), jax.device_put(values, sharding)
+
+
+def put_entries(*arrays):
+    """Device-put one serve batch's flat entry arrays and lambda indices
+    as one batched transfer (the door for request operands that are not
+    slabs: O(nnz) bytes, never budgeted buckets). Returns the device
+    arrays as a tuple, in order."""
+    return tuple(jax.device_put(arrays))
 
 
 @dataclass

@@ -193,8 +193,9 @@ def slab_spmv(rows, vals, d, *, n_loc: int):
 
 
 def slab_path_spmv(rows, vals, lam_idx, betas, *, n_loc: int):
-    """Per-example-lambda slab SpMV: the serving layer's batched scoring
-    primitive (``repro.serve``).
+    """Per-example-lambda slab SpMV: the batched scoring primitive of
+    the mesh serving branch (``repro.serve``; local serving scores entry
+    lists with :func:`entry_path_spmv`).
 
     rows/vals: (T, K) by-feature request slab with *local* example (=
     request row) indices, sentinel ``n_loc``; ``lam_idx`` (n_loc,) int32
@@ -225,6 +226,35 @@ def slab_path_spmv(rows, vals, lam_idx, betas, *, n_loc: int):
     out = jnp.zeros(n_loc + 1, jnp.float32)
     out = out.at[safe.reshape(-1)].add(dv.reshape(-1))
     return out[:n_loc]
+
+
+def entry_path_spmv(rows, feats, vals, lam_idx, betas):
+    """Per-row-lambda scores from a flat entry list: the local serving
+    path's batched scoring primitive (``repro.serve``).
+
+    rows/feats/vals: (N,) entries — request row, feature, value — with
+    row ``n = lam_idx.shape[0]`` marking padding; ``lam_idx`` (n,) int32
+    picks each row's operating point in the stacked ``betas`` (L, p)
+    coefficient path. Returns the (n,) scores
+    ``out[i] = sum_e vals[e] * betas[lam_idx[i], feats[e]] [rows[e] == i]``.
+    O(N) work and operands, whatever the feature width.
+
+    Padding entries are masked on the validity predicate, so any value
+    parked on them scores exactly zero. The row sum is a float32 scatter-
+    add in entry order: on CPU a row whose entries ascend by feature sums
+    its terms in the order :func:`slab_spmv` does, so the scores are bit-
+    identical to it on the same batch's slabs; on TPU the add order is
+    the compiler's, so the scores agree within float32 rounding.
+    """
+    n = lam_idx.shape[0]
+    valid = rows < n
+    with jax.named_scope("entry_gather"):
+        li = jnp.take(lam_idx, jnp.minimum(rows, n - 1))
+        b = betas.astype(jnp.float32)[li, feats]
+    c = jnp.where(valid, vals.astype(jnp.float32) * b, 0.0)
+    out = jnp.zeros(n + 1, jnp.float32)
+    out = out.at[jnp.minimum(rows, n)].add(c)
+    return out[:n]
 
 
 def slab_corr(rows, vals, v):

@@ -103,25 +103,54 @@ def serve_loop(scorer, batcher, reqs, lams, *, steps: int):
     return total, time.perf_counter() - t0, versions
 
 
+#: served-vs-``decision_function`` bound where the two sum a row's terms
+#: in different orders (the local path on a TPU): the float32 limit on
+#: |served - reference| / sum |beta v| that the serving benchmark holds
+#: every served score to
+SCORE_GAP = 1e-5
+
+
 def smoke_check(est, store, scorer, batch, n_live: int, path) -> None:
-    """Served-vs-``decision_function`` bit-equality at every lambda."""
+    """Served scores against ``decision_function`` on the batch's slabs at
+    every lambda: bit-equal where both sum each row in the same order (on
+    the CPU, and through the mesh branch, which runs the same slab
+    kernel); on a TPU's local path, which sums the entry list in another
+    order, within ``SCORE_GAP`` of sum |beta v| per row."""
     inner = SlabDesign(jnp.asarray(batch.row_idx),
                        jnp.asarray(batch.values), batch.batch_cap)
     design = (ShardedDesign(inner, store.mesh, tile=store.tile)
               if store.mesh is not None else inner)
+    exact = store.mesh is not None or jax.default_backend() != "tpu"
+    n = batch.n_entries
+    worst = 0.0
     for l in range(len(path)):
         beta = path.betas[l]
         if batch.p_pad != beta.shape[0]:
             beta = jnp.pad(beta, (0, batch.p_pad - beta.shape[0]))
-        # allow[nonfinite-guard]: decision_function is the reference oracle; the served side of the bit-equality IS the guarded path
+        # allow[nonfinite-guard]: decision_function is the reference oracle; the served side of the comparison IS the guarded path
         ref = np.asarray(est.decision_function(design, beta=beta))[:n_live]
         got, _ = scorer.score(batch, np.full(n_live, path.lambdas[l]))
-        if not np.array_equal(got, ref):
+        if exact:
+            if not np.array_equal(got, ref):
+                raise SystemExit(
+                    f"FAIL: served scores not bit-equal to decision_function "
+                    f"at lambda index {l} "
+                    f"(max |diff| {np.max(np.abs(got - ref)):.3e})")
+            continue
+        terms = np.abs(np.asarray(beta, np.float64)[batch.entry_feat[:n]]
+                       * batch.entry_val[:n])
+        scale = np.bincount(batch.entry_row[:n], weights=terms,
+                            minlength=batch.batch_cap)[:n_live]
+        gap = np.abs(got.astype(np.float64) - ref)
+        worst = max(worst, float(np.max(gap)))
+        if np.any(gap > SCORE_GAP * scale):
             raise SystemExit(
-                f"FAIL: served scores not bit-equal to decision_function "
-                f"at lambda index {l} "
-                f"(max |diff| {np.max(np.abs(got - ref)):.3e})")
-    print(f"# smoke: served scores bit-equal to decision_function at all "
+                f"FAIL: served scores differ from decision_function by more "
+                f"than {SCORE_GAP} of sum |beta v| at lambda index {l} "
+                f"(max |diff| {np.max(gap):.3e})")
+    how = ("bit-equal to" if exact else
+           f"within {SCORE_GAP} of sum |beta v| (max |diff| {worst:.3e}) of")
+    print(f"# smoke: served scores {how} decision_function at all "
           f"{len(path)} lambdas")
 
 

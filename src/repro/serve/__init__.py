@@ -7,14 +7,19 @@ certified regularization path); this package serves it:
   (replicated locally, P(model)-feature-sharded on a mesh), versioned,
   hot-swappable without dropping in-flight batches;
 * :mod:`~repro.serve.ingest` — deterministic hashed sparse-feature
-  ingestion packing request batches into the training kernels' by-feature
-  slab layout;
+  ingestion packing request batches into flat entry lists (request row,
+  feature, value per nonzero: O(nnz), whatever the width), with the
+  training kernels' by-feature slabs built from them on demand for the
+  mesh branch;
 * :class:`RequestBatcher` — accumulate/drain batching with power-of-two
   shape classes, a bounded pending queue (:class:`Overloaded` admission
   control) and per-request deadlines shed at drain;
-* :class:`PathScorer` — one jitted ``slab_path_spmv`` dispatch per batch,
-  each request row picking its own lambda operating point on device;
-  scores bit-identical to ``LogisticL1.decision_function``. Non-finite
+* :class:`PathScorer` — one jitted dispatch per batch (locally
+  ``entry_path_spmv`` over the entry list, on a mesh ``slab_path_spmv``
+  over the slabs), each request row picking its own lambda operating
+  point on device; scores bit-identical to
+  ``LogisticL1.decision_function`` on the CPU and through the mesh, equal
+  to float32 rounding on a TPU's local path. Non-finite
   scores quarantine the published version and pin the store back to its
   last-good snapshot (:class:`NonFiniteScores` only if that fails too).
 

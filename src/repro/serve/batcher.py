@@ -6,10 +6,11 @@ scoring dispatch. Two shape-bounding rules keep the compiled-program count
 small over a serving process's lifetime:
 
 * the batch extent is quantized to power-of-two capacity classes
-  (:func:`batch_capacity`) up to ``max_batch``, mirroring the slab-K
-  classes of :func:`~repro.serve.ingest.k_capacity`;
+  (:func:`batch_capacity`) up to ``max_batch``, and each capacity's
+  entry list to power-of-two classes floored per row
+  (:func:`~repro.serve.ingest.entry_capacity`);
 * hashing/encoding happens at ``submit`` time (spreading the host work
-  across arrivals), packing at ``drain`` time (one vectorized pass).
+  across arrivals), packing at ``drain`` time (one O(nnz) concatenation).
 
 The queue is *bounded*: ``max_pending`` caps admission (``submit`` raises
 :class:`Overloaded` instead of growing without limit under a stalled

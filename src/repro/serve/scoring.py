@@ -1,21 +1,23 @@
 """Batched path scoring: one jitted dispatch per request batch.
 
-The scoring step is ``kernels.ops.slab_path_spmv`` over a
-:class:`~repro.serve.ingest.PackedBatch` — the by-feature slab layout the
-training kernels consume, request rows playing the example axis, each row
-gathering its own operating point from the store's stacked ``(L, p)``
-coefficients. Locally that is one jitted call; on a mesh it is the same
-``shard_map`` shape as ``core.distributed.make_slab_margins`` (feature
-shards run the slab kernel, one psum over ``model`` assembles the scores)
-with the beta *stack* left P(model)-sharded in place. Either way exactly
-one program launches per batch and only the ``(batch,)`` scores travel to
-host.
+Locally the scoring step is ``kernels.ops.entry_path_spmv`` over a
+:class:`~repro.serve.ingest.PackedBatch`'s entry list: each nonzero
+gathers its coefficient from the store's stacked ``(L, p)`` path at its
+row's operating point, and each row sums its entries — O(nnz) device
+work and host->device bytes per batch, whatever the feature width. On a
+mesh it is the same ``shard_map`` shape as
+``core.distributed.make_slab_margins`` over the batch's by-feature slabs
+(feature shards run ``kernels.ops.slab_path_spmv``, one psum over
+``model`` assembles the scores) with the beta *stack* left P(model)-
+sharded in place. Either way exactly one program launches per batch and
+only the ``(batch,)`` scores travel to host.
 
-Because the per-entry coefficient gather feeds the *same* masking/scatter
-machinery as ``slab_spmv`` (see ``slab_path_spmv``'s docstring), a batch
-whose rows all request lambda ``l`` scores bit-identically to
-``LogisticL1.decision_function(design, beta=path[l])`` on the same slabs —
-locally and through the mesh.
+A batch whose rows all request lambda ``l`` scores bit-identically to
+``LogisticL1.decision_function(design, beta=path[l])`` on the same
+batch's slabs through the mesh, and locally on the CPU, where the entry
+path's scatter-add sums each row's terms in the slab kernel's order. On
+the TPU the scatter's add order is the compiler's, so the local path and
+the slab kernel agree to float32 rounding.
 """
 from __future__ import annotations
 
@@ -24,13 +26,13 @@ from functools import partial
 from typing import Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops as kops
+from repro.obs import registry as obs_registry
 from repro.obs import trace as obs_trace
 from repro.resilience import serve_delay
-from repro.serve.ingest import PackedBatch
+from repro.serve.ingest import ENTRY_FLOOR_PER_ROW, PackedBatch
 from repro.serve.store import PathStore, StoreSnapshot
 
 
@@ -42,9 +44,10 @@ class NonFiniteScores(RuntimeError):
     itself is suspect."""
 
 
-@partial(jax.jit, static_argnames=("n_loc",))
-def _score_local(rows, vals, lam_idx, betas, *, n_loc: int):
-    return kops.slab_path_spmv(rows, vals, lam_idx, betas, n_loc=n_loc)
+@jax.jit
+def _score_local(entry_row, entry_feat, entry_val, lam_idx, betas):
+    return kops.entry_path_spmv(entry_row, entry_feat, entry_val, lam_idx,
+                                betas)
 
 
 def make_path_margins(mesh, n_loc: int, model_axis: str = "model"):
@@ -134,11 +137,15 @@ class PathScorer:
         The ``score`` span closes at the existing ``np.asarray`` host
         sync on the scores — tracing adds no extra device->host hop. Its
         children, each tagged with the batch's ``batch_id``, split it:
-        ``put`` places the slabs and ``lam_idx`` on the device (``bytes``
-        placed), ``launch`` enqueues the scoring program (and holds any
-        ``compile``), ``fetch`` is that ``np.asarray``: the device's run
-        and the copy back. Its self time is the snapshot read, lambda
-        resolution and the finite check.
+        ``put`` places the batch's operands and ``lam_idx`` on the device
+        (``bytes`` placed; locally also ``entries``, the live nonzeros,
+        and ``slots``, the entry class), ``launch`` enqueues the scoring
+        program (and holds any ``compile``), ``fetch`` is that
+        ``np.asarray``: the device's run and the copy back. Its self time
+        is the snapshot read, lambda resolution and the finite check.
+        Locally, a batch whose entry class is above the floor
+        (``ENTRY_FLOOR_PER_ROW`` per row) counts on the
+        ``serve.entry_class_over_floor`` counter: its shape may compile.
         """
         with obs_trace.span("score", rows=int(batch.n_live),
                             batch=batch.batch_id) as sp:
@@ -187,22 +194,26 @@ class PathScorer:
         returns the device scores."""
         with obs_trace.span("put", batch=batch.batch_id) as sp:
             fn, args = self._place(batch, lam_idx)
-            sp.set(bytes=batch.row_idx.nbytes + batch.values.nbytes
-                   + lam_idx.nbytes)
+            sp.set(bytes=sum(a.nbytes for a in args))
+            if self.store.mesh is None:
+                slots = int(batch.entry_row.shape[0])
+                sp.set(entries=batch.n_entries, slots=slots)
+                if slots > ENTRY_FLOOR_PER_ROW * batch.batch_cap:
+                    obs_registry.counter("serve.entry_class_over_floor").inc()
         with obs_trace.span("launch", batch=batch.batch_id):
             return fn(*args, snap.betas)
 
     def _place(self, batch: PackedBatch, lam_idx: np.ndarray):
-        """``(program, device operands)`` of the batch's scoring step."""
+        """``(program, device operands)`` of the batch's scoring step:
+        locally the entry list, on a mesh the slabs."""
         mesh = self.store.mesh
         if mesh is None:
-            if batch.dp != 1:
-                raise ValueError(
-                    f"local scoring needs dp=1 slabs, got dp={batch.dp}")
-            return partial(_score_local, n_loc=batch.batch_cap), (
-                jnp.asarray(batch.row_idx[:, 0, :]),
-                jnp.asarray(batch.values[:, 0, :]),
-                jnp.asarray(lam_idx))
+            # request entries go through the residency module's door, as
+            # the mesh branch's slabs do (bucket-residency rule)
+            from repro.data.residency import put_entries
+
+            return _score_local, put_entries(
+                batch.entry_row, batch.entry_feat, batch.entry_val, lam_idx)
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from repro.core.distributed import _data_axes, _data_extent

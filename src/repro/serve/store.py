@@ -4,8 +4,10 @@ The training side certifies a path — L lambda operating points, each with
 its own sparsity/quality trade-off. Serving keeps the ENTIRE stacked
 ``(L, p)`` coefficient array device-resident (replicated locally,
 P(model)-feature-sharded on a mesh) so every request picks its lambda at
-scoring time with zero host traffic: the scoring step gathers the chosen
-row per request *inside* the kernel (``kernels.ops.slab_path_spmv``).
+scoring time with zero host traffic: the scoring step gathers each
+nonzero's coefficient from its request's row *on the device*
+(``kernels.ops.entry_path_spmv`` over a batch's entry list locally,
+``kernels.ops.slab_path_spmv`` over its slabs on a mesh).
 
 Hot-swap: :meth:`PathStore.swap` installs a freshly certified path (a new
 ``PathResult`` from a background refit, or the next points of a still-

@@ -399,8 +399,30 @@ def test_put_bytes_are_the_arrays_placed():
         batch = round_([{"a": 1.0}, {"b": 2.0}])
     (put,) = _by_name(tr.spans)["put"]
     lam_idx_nbytes = batch.batch_cap * np.dtype(np.int32).itemsize
-    assert put["args"]["bytes"] == (batch.row_idx.nbytes
-                                    + batch.values.nbytes + lam_idx_nbytes)
+    assert put["args"]["bytes"] == (
+        batch.entry_row.nbytes + batch.entry_feat.nbytes
+        + batch.entry_val.nbytes + lam_idx_nbytes)
+
+
+def test_put_counts_entries_slots_and_classes_over_the_floor():
+    from repro.serve.ingest import ENTRY_FLOOR_PER_ROW
+
+    p = 1 << 16
+    round_ = _serve_round(p)
+    floor = ENTRY_FLOOR_PER_ROW * 8            # batch capacity 8
+    big = {f"w{i}": 1.0 for i in range(floor + 400)}
+    reg, tr = MetricsRegistry(), Tracer()
+    with obs_registry.use_registry(reg), obs_trace.use_tracer(tr):
+        small = round_([{"a": 1.0, "b": 2.0}, {"c": 0.5}])
+        assert reg.value("serve.entry_class_over_floor") is None
+        large = round_([big])
+    put_small, put_large = _by_name(tr.spans)["put"]
+    assert put_small["args"]["entries"] == small.n_entries == 3
+    assert put_small["args"]["slots"] == floor
+    assert floor < large.n_entries <= len(big)
+    assert put_large["args"]["entries"] == large.n_entries
+    assert put_large["args"]["slots"] == 2 * floor
+    assert reg.value("serve.entry_class_over_floor") == 1
 
 
 def test_drain_wait_is_the_summed_queue_wait():

@@ -278,6 +278,135 @@ def test_served_scores_bit_equal_decision_function(fitted):
         assert np.array_equal(mixed[rows], uni[rows])
 
 
+# ---------------------------------------------------------------------------
+# entry-list scoring: O(nnz) operands, padding inert, one class per capacity
+# ---------------------------------------------------------------------------
+
+def _ref_scores(docs, lam_rows, betas):
+    """Independent float64 re-score: CRC-32 re-hash of every token,
+    colliding values summed; returns (scores, sum |beta v|) per doc."""
+    import zlib
+
+    p = betas.shape[1]
+    ref, scale = [], []
+    for doc, l in zip(docs, lam_rows):
+        acc = {}
+        for tok, v in doc.items():
+            j = zlib.crc32(tok.encode("utf-8")) % p
+            acc[j] = acc.get(j, 0.0) + float(v)
+        idx = np.fromiter(acc, np.int64, len(acc))
+        val = np.fromiter(acc.values(), np.float64, len(acc))
+        coef = betas[l, idx].astype(np.float64)
+        ref.append(float(coef @ val))
+        scale.append(float(np.abs(coef) @ np.abs(val)))
+    return np.asarray(ref), np.asarray(scale)
+
+
+@pytest.mark.parametrize("n_req, cap", [(5, 8), (13, 16)])
+def test_entry_scores_match_float64_rescore(n_req, cap):
+    p, L = 4099, 4
+    rng = np.random.default_rng(n_req)
+    betas = rng.normal(size=(L, p)).astype(np.float32)
+    betas[:, rng.random(p) < 0.5] = 0.0
+    path = PathResult(
+        lambdas=2.0 ** -np.arange(1, L + 1), betas=jnp.asarray(betas),
+        nnz=np.zeros(L, np.int64), f=np.zeros(L), n_iters=np.ones(L, np.int64),
+        metrics=[{}] * L, screen=[{}] * L)
+    docs = [{f"w{rng.integers(0, 50_000)}": float(rng.uniform(0.05, 1.0))
+             for _ in range(rng.integers(1, 120))} for _ in range(n_req)]
+    lam_rows = rng.integers(0, L, n_req)
+    batcher = RequestBatcher(p, max_batch=16)
+    for d, l in zip(docs, lam_rows):
+        batcher.submit(d, float(path.lambdas[l]))
+    batch, lams = batcher.drain()
+    assert batch.batch_cap == cap and batch.n_live == n_req
+    got, _ = PathScorer(PathStore(path)).score(batch, lams)
+    ref, scale = _ref_scores(docs, lam_rows, betas)
+    gap = np.abs(got.astype(np.float64) - ref)
+    assert np.all(gap <= 1e-5 * scale), np.max(gap / np.maximum(scale, 1e-30))
+
+
+def test_entry_padding_scores_exactly_zero_under_adversarial_values():
+    from dataclasses import replace
+
+    from repro.kernels import ops as kops
+
+    p = 64
+    path = _tiny_path(p)
+    batch = pack_requests([encode_request({"a": 1.0, "b": -2.0}, p),
+                           encode_request({"c": 0.5}, p)], p, batch_cap=8)
+    n = batch.n_entries
+    clean, _ = PathScorer(PathStore(path)).score(batch, np.ones(2))
+    for bad in (1e30, np.nan, -np.inf):
+        val = batch.entry_val.copy()
+        val[n:] = bad
+        feat = batch.entry_feat.copy()
+        feat[n:] = p - 1                      # a live coefficient
+        poisoned = replace(batch, entry_val=val, entry_feat=feat)
+        got, _ = PathScorer(PathStore(path)).score(poisoned, np.ones(2))
+        assert np.array_equal(got, clean), bad
+        # the padding rows of the full (batch_cap,) output are exact zeros
+        full = np.asarray(kops.entry_path_spmv(
+            jnp.asarray(poisoned.entry_row), jnp.asarray(feat),
+            jnp.asarray(val), jnp.zeros(8, jnp.int32), path.betas))
+        assert np.all(full[2:] == 0.0) and np.all(np.isfinite(full))
+
+
+def test_empty_and_all_zero_requests_score_zero_through_entries():
+    p = 16
+    batch = pack_requests([encode_request({}, p),
+                           encode_request({"x": 0.0, "y": 0.0}, p),
+                           encode_request({}, p)], p, batch_cap=8)
+    assert batch.n_entries == 0
+    assert np.all(batch.entry_row == batch.batch_cap)
+    assert not np.any(batch.entry_val)
+    scores, _ = PathScorer(PathStore(_tiny_path(p))).score(batch, np.ones(3))
+    assert np.array_equal(scores, np.zeros(3, np.float32))
+    assert "_slabs" not in batch.__dict__     # local scoring built no slab
+
+
+def test_pack_entry_bytes_do_not_grow_with_width():
+    rng = np.random.default_rng(5)
+    encoded = [encode_request({f"t{rng.integers(0, 10**6)}": 1.0
+                               for _ in range(rng.integers(1, 200))}, 47_236)
+               for _ in range(16)]
+
+    def entry_bytes(batch):
+        return (batch.entry_row.nbytes + batch.entry_feat.nbytes
+                + batch.entry_val.nbytes)
+
+    narrow = pack_requests(encoded, 47_236, batch_cap=16)
+    wide = pack_requests(encoded, 16_609_143, batch_cap=16)
+    assert entry_bytes(narrow) == entry_bytes(wide) == 4096 * 12
+    for a in ("entry_row", "entry_feat", "entry_val"):
+        assert np.array_equal(getattr(narrow, a), getattr(wide, a))
+    assert "_slabs" not in wide.__dict__
+
+
+def test_rcv1_like_window_meets_one_entry_class_per_capacity():
+    from repro.serve.ingest import ENTRY_FLOOR_PER_ROW, entry_capacity
+
+    p, sigma, mean = 47_236, 0.7, 74
+    rng = np.random.default_rng(11)
+    lens = np.clip(np.round(rng.lognormal(np.log(mean) - sigma ** 2 / 2,
+                                          sigma, 20_000)), 5, 1200)
+    classes, at = {}, 0
+    while at < lens.size:
+        b = int(rng.integers(1, 17))
+        chunk = lens[at:at + b].astype(np.int64)
+        at += b
+        encoded = [(np.sort(rng.choice(p, k, replace=False)),
+                    np.ones(k, np.float32)) for k in chunk]
+        cap = batch_capacity(len(encoded), b_max=16)
+        batch = pack_requests(encoded, p, batch_cap=cap)
+        assert batch.n_entries == int(chunk.sum())
+        classes.setdefault(cap, set()).add(batch.entry_row.shape[0])
+    assert classes == {8: {ENTRY_FLOOR_PER_ROW * 8},
+                       16: {ENTRY_FLOOR_PER_ROW * 16}}
+    assert entry_capacity(ENTRY_FLOOR_PER_ROW * 16 + 1, 16) == (
+        2 * ENTRY_FLOOR_PER_ROW * 16)
+
+
 def test_scorer_validates_geometry(fitted):
     X, _, _, path = fitted
     p = X.shape[1]

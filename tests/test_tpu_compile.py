@@ -1,5 +1,6 @@
-"""Every Pallas kernel of the main path, compiled by the TPU compiler for a
-described (not attached) v5e chip at the widths ``chip_smoke.py`` drives.
+"""Every Pallas kernel of the main path, and the local serving program,
+compiled by the TPU compiler for a described (not attached) v5e chip at
+the widths ``chip_smoke.py`` and the serving benchmark drive.
 
 Interpret mode accepts slices and block shapes that Mosaic refuses (a
 dynamic slice on the lane axis, a ``(1, 1)`` output block); these compiles
@@ -101,8 +102,8 @@ def test_slab_gram_compiles_for_v5e(one_chip, k):
     (128, 32, 80_000),
     (128, 34, 80_000),
     (1024, 34, 80_000),       # whole-design margins, 2x2 mesh shard
-    (2000, 8, 128),           # serve: the (p, K) request slab it runs
-    (2000, 8, 64),            # serve: other batch capacity classes
+    (2000, 8, 128),           # serve smoke: decision_function's request
+    (2000, 8, 64),            # slabs, at each batch capacity class
     (2000, 8, 256),
     (2000, 16, 512),
 ])
@@ -119,3 +120,18 @@ def test_logistic_stats_compiles_for_v5e(one_chip):
     compiled = logistic_stats_pallas.lower(vec, vec,
                                            interpret=False).compile()
     _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("cap,n", [(8, 2048), (16, 4096)])
+def test_entry_path_spmv_compiles_for_v5e(one_chip, cap, n):
+    """The local serving program at rcv1.docs-max's two shapes (batch
+    capacity 8 and 16 at 256 entries per row, 47,236 features, 8 path
+    points): plain XLA, no kernel."""
+    from repro.kernels import ops as kops
+
+    i32 = _sds((n,), jnp.int32, one_chip)
+    text = jax.jit(kops.entry_path_spmv).lower(
+        i32, i32, _sds((n,), jnp.float32, one_chip),
+        _sds((cap,), jnp.int32, one_chip),
+        _sds((8, 47_236), jnp.float32, one_chip)).compile().as_text()
+    assert "tpu_custom_call" not in text
